@@ -86,11 +86,10 @@ def _timeit(fn, *args, n: int = 5, warmup: int = 2) -> float:
 def bench_hash_flops():
     """§1 footnote: 'we consider 20 FLOPS per hash, but this can be 20000
     on a modern CPU'."""
-    from repro.core.compat import cost_analysis_dict
     from repro.kernels.ops import sha256_words
     msg = jnp.zeros((4096, 20), jnp.uint32)           # 80-byte headers
     lowered = jax.jit(lambda m: sha256_words(m)).lower(msg)
-    cost = cost_analysis_dict(lowered.cost_analysis())
+    cost = lowered.cost_analysis()
     flops_per_hash = float(cost.get("flops", 0.0)) / msg.shape[0]
     us = _timeit(jax.jit(lambda m: sha256_words(m)), msg)
     hashes_per_s = msg.shape[0] / (us * 1e-6)
@@ -1066,4 +1065,7 @@ if __name__ == "__main__":
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true",
                    help="fast CI subset (commit pipeline only, small N)")
-    main(smoke=p.parse_args().smoke)
+    args = p.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+    main(smoke=args.smoke)

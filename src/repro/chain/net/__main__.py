@@ -25,6 +25,12 @@ through ``Node.recover``, redials the seed on a fresh port, resyncs
 the lost tail headers-first over TCP, and must still land on the
 oracle digest.
 
+Every process of the demo runs JAX on the CPU (``_PLATFORM``), and each
+report names the platform it used.  A chip belongs to one process at a
+time, so N workers cannot share one; and the GAN blocks of the suite
+replay floats, so every worker and the oracle must compute on the same
+platform to agree bit for bit.
+
 Exit status 0 iff every chain converged AND matched the in-process
 oracle.
 """
@@ -39,6 +45,8 @@ import sys
 import tempfile
 import time
 
+import jax
+
 from repro.chain.net.identity import make_addr, make_identities
 from repro.chain.net.peer import (_SUITE_SCHEDULE, PeerNode, _suite_node,
                                   chain_digest)
@@ -48,6 +56,8 @@ from repro.chain.store import ChainStore
 
 _RESULT_PREFIX = "RESULT "
 _HOST = "127.0.0.1"
+# the one platform every process of the demo computes on (module docstring)
+_PLATFORM = "cpu"
 
 
 def _build_peer(idx: int, n_peers: int, *, suite_seed: int,
@@ -147,6 +157,7 @@ async def _mine_loop(peer: PeerNode, transport: TcpTransport, idx: int,
 def _report(peer: PeerNode, transport: TcpTransport, role: str) -> dict:
     out = {
         "role": role,
+        "platform": jax.devices()[0].platform,
         "height": peer.node.ledger.height,
         "chain_digest": chain_digest(peer.node),
         "book": sorted(peer.node.book.balances.items()),
@@ -340,6 +351,8 @@ async def _run_parent(*, n_peers: int, suite_seed: int, timeout: float,
         brief = {k: report[k] for k in
                  ("n_peers", "converged", "oracle_match",
                   "height", "elapsed_s")}
+        brief["platforms"] = [report["parent"]["platform"]] + [
+            r["platform"] for r in child_reports]
         if chaos:
             brief["fault"] = fault
             brief["recovered"] = report["recovered"]
@@ -381,6 +394,8 @@ def main(argv=None) -> int:
                          "suite)")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    # before any JAX computation of this process (parent or child)
+    jax.config.update("jax_platforms", _PLATFORM)
     schedule = tuple(f for f in args.schedule.split(",") if f)
     if args.peers < 2:
         ap.error("--peers must be >= 2")

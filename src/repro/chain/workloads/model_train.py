@@ -9,7 +9,10 @@ attached — and commits:
 * ``state_digest`` — sha256 of the canonical post-block params bytes
   (``train.steps.params_digest``: gathered to host, little-endian,
   dtype+shape framed, so a 1-device CPU node and an 8-way FSDP node
-  commit identical digests for identical weights);
+  commit identical digests for identical weights — but a step trained
+  on a 4-device FSDP mesh does not produce the weights a one-device step
+  does, so such a block fails verification on a node with another mesh
+  shape; ROADMAP R4);
 * ``merkle_root`` — over per-microstep leaves
   ``height | micro | batch_digest | metrics_digest``, with the raw
   digest pairs shipped as ``BlockPayload.micro_proof`` evidence;

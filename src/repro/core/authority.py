@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import cost_analysis_dict
 from repro.core.jash import Jash, JashMeta, JashValidationError
 from repro.kernels.ops import sha256_words
 
@@ -68,7 +67,7 @@ class RuntimeAuthority:
         jash.validate(loop_bound=self.loop_bound)
 
         compiled = jash.lower_compile()
-        cost = cost_analysis_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         flops = float(cost.get("flops", 0.0))
 
         # runtime estimation on random inputs (paper: "estimating mean

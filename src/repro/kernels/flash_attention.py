@@ -69,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, bq: int = 512,
-                           bk: int = 512, interpret: bool = True
+                           bk: int = 512, interpret: bool
                            ) -> jax.Array:
     """q: (BH, S, hd); k, v: (BH, T, hd) -> (BH, S, hd).
 

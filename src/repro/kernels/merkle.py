@@ -111,8 +111,8 @@ def _pad_block_schedule() -> List[int]:
 
 
 # K[t] + W[t] folded into one constant per round of the padding block
-_KW = tuple((int(k) + w) & 0xFFFFFFFF
-            for k, w in zip(_K, _pad_block_schedule()))
+_KW = np.array([(int(k) + w) & 0xFFFFFFFF
+                for k, w in zip(_K, _pad_block_schedule())], np.uint32)
 
 
 def _rotr(x, n):
@@ -130,37 +130,39 @@ def _round(s, kw):
 
 
 def _node_hash(w16):
-    """SHA-256 of 64-byte messages given as 16 word rows of (n,) lanes."""
+    """SHA-256 of 64-byte messages given as 16 word rows of (n,) lanes.
+
+    The 64 rounds of each block are a ``fori_loop``, not unrolled: XLA
+    compiles a fully unrolled 128-round chain per tree level in minutes,
+    and a whole tree's worth of them not at all."""
     n = w16[0].shape[0]
     init = tuple(jnp.full((n,), h, jnp.uint32) for h in _H0)
-    # block 1: the message, rolling 64-entry schedule
-    w = list(w16)
-    s = init
-    for t in range(64):
-        if t >= 16:
-            s0 = _rotr(w[t - 15], 7) ^ _rotr(w[t - 15], 18) \
-                ^ (w[t - 15] >> jnp.uint32(3))
-            s1 = _rotr(w[t - 2], 17) ^ _rotr(w[t - 2], 19) \
-                ^ (w[t - 2] >> jnp.uint32(10))
-            w.append(w[t - 16] + s0 + w[t - 7] + s1)
-        s = _round(s, w[t] + jnp.uint32(int(_K[t])))
+    k, kw = jnp.asarray(_K), jnp.asarray(_KW)
+
+    # block 1: the message, rolling 16-word schedule (w[0] is W[t])
+    def msg_round(t, carry):
+        s, w = carry
+        s = _round(s, w[0] + k[t])
+        s0 = _rotr(w[1], 7) ^ _rotr(w[1], 18) ^ (w[1] >> jnp.uint32(3))
+        s1 = _rotr(w[14], 17) ^ _rotr(w[14], 19) ^ (w[14] >> jnp.uint32(10))
+        return s, w[1:] + (w[0] + s0 + w[9] + s1,)
+
+    s, _ = jax.lax.fori_loop(0, 64, msg_round, (init, tuple(w16)))
     mid = tuple(x + y for x, y in zip(init, s))
     # block 2: constant padding, precomputed K+W schedule
-    s = mid
-    for t in range(64):
-        s = _round(s, jnp.uint32(_KW[t]))
+    s = jax.lax.fori_loop(0, 64, lambda t, s: _round(s, kw[t]), mid)
     return tuple(x + y for x, y in zip(mid, s))
 
 
-# Bounded: each entry is a fully-unrolled executable compiled per leaf
+# Bounded: each entry is an executable compiled per leaf
 # count (static shapes are what make the dispatch fast); the bound keeps a
 # workload with many distinct block sizes from accumulating executables
 # forever.
 @functools.lru_cache(maxsize=32)
 def _tree_fn(n: int, keep_levels: bool):
     """Jitted device reduction of an (8, n) words-major digest level down
-    to width <= ``_CUTOVER``.  Levels are unrolled at trace time (the tree
-    shape is static).  Root path returns only the boundary level; with
+    to width <= ``_CUTOVER``.  Levels are traced one after another (the
+    tree shape is static).  Root path returns only the boundary level; with
     ``keep_levels`` every intermediate level comes back already odd-padded
     — exactly the rows a proof's sibling lookup indexes into — except the
     last (the host continues from it)."""
@@ -241,10 +243,9 @@ def merkle_root_from_digests(digests: np.ndarray | jax.Array) -> str:
 
 
 # Bounded like ``_tree_fn``: one executable per per-block leaf count
-# (the batch dimension is specialized inside jax.jit).  Unroll depth —
-# the dominant CPU compile cost, ~tens of seconds per level on the dev
-# container — matches ``_tree_fn`` exactly: levels stop at ``_CUTOVER``
-# per-block width and the narrow tops finish on the host.
+# (the batch dimension is specialized inside jax.jit).  Levels stop at
+# ``_CUTOVER`` per-block width exactly as in ``_tree_fn``, and the narrow
+# tops finish on the host.
 @functools.lru_cache(maxsize=32)
 def _forest_fn(width: int):
     """Jitted reduction of a *forest*: (8, B, W) words-major digest

@@ -1,10 +1,13 @@
 """Jitted public wrappers around the Pallas kernels, with batch padding,
-sequence chunking, and an automatic jnp fallback.
+sequence chunking, and a pure-jnp path.
 
-``interpret`` defaults to True on CPU (this container) and False on real
-TPU; the pure-jnp reference path (``backend="jnp"``) is what the model
-forward uses by default so the 512-device dry-run lowers to plain HLO
-(DESIGN.md §5).
+This module is the one place that decides how a Pallas kernel runs:
+``backend="pallas"`` compiles the kernel for the TPU unless the caller
+asks for the Pallas interpreter by name (``interpret=True``, what the CPU
+tests do).  Nothing falls back to interpret mode on its own, so a kernel
+on the chip path never runs interpreted by accident.  The pure-jnp
+reference path (``backend="jnp"``) is what the model forward uses by
+default so the 512-device dry-run lowers to plain HLO (DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -21,17 +24,14 @@ from repro.kernels.sha256 import TILE_N, sha256_pallas
 from repro.kernels.wkv6 import wkv6_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # sha256
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def sha256_words(msg: jax.Array, backend: str = "jnp") -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
+def sha256_words(msg: jax.Array, backend: str = "jnp",
+                 interpret: bool = False) -> jax.Array:
     """msg: uint32 (N, W) -> (N, 8) digests.  backend: "jnp" | "pallas"."""
     if backend == "jnp":
         return _ref.sha256_words_ref(msg)
@@ -41,7 +41,7 @@ def sha256_words(msg: jax.Array, backend: str = "jnp") -> jax.Array:
     if pad_n:
         padded = jnp.concatenate(
             [padded, jnp.zeros((pad_n, padded.shape[1]), jnp.uint32)], axis=0)
-    out = sha256_pallas(padded, interpret=not _on_tpu())
+    out = sha256_pallas(padded, interpret=interpret)
     return out[:N]
 
 
@@ -50,10 +50,12 @@ def sha256_words(msg: jax.Array, backend: str = "jnp") -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "backend", "bq", "bk"))
+@functools.partial(jax.jit, static_argnames=("causal", "backend", "bq", "bk",
+                                             "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, backend: str = "jnp",
-                    bq: int = 512, bk: int = 512) -> jax.Array:
+                    bq: int = 512, bk: int = 512,
+                    interpret: bool = False) -> jax.Array:
     """q: (B, S, H, hd); k, v: (B, T, Kv, hd) -> (B, S, H, hd).
 
     GQA: kv heads are broadcast to H inside the fold.  backend "jnp"
@@ -69,7 +71,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], hd)
     out = flash_attention_pallas(fold(q), fold(kx), fold(vx),
                                  causal=causal, bq=bq, bk=bk,
-                                 interpret=not _on_tpu())
+                                 interpret=interpret)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
 
@@ -78,10 +80,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "seq_chunk"))
+@functools.partial(jax.jit, static_argnames=("backend", "seq_chunk",
+                                             "interpret"))
 def decay_scan(a: jax.Array, b: jax.Array, h0: jax.Array | None = None,
-               backend: str = "jnp", seq_chunk: int = 2048
-               ) -> Tuple[jax.Array, jax.Array]:
+               backend: str = "jnp", seq_chunk: int = 2048,
+               interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """h_t = a_t h_{t-1} + b_t.  a, b: (B, S, C).  Returns (h, h_last)."""
     B, S, C = a.shape
     if h0 is None:
@@ -98,7 +101,7 @@ def decay_scan(a: jax.Array, b: jax.Array, h0: jax.Array | None = None,
     for s0 in range(0, S, seq_chunk):
         sl = slice(s0, min(s0 + seq_chunk, S))
         o, h = decay_scan_pallas(a[:, sl], b[:, sl], h,
-                                 interpret=not _on_tpu())
+                                 interpret=interpret)
         outs.append(o)
     out = jnp.concatenate(outs, axis=1)[..., :C]
     return out, h[..., :C]
@@ -109,9 +112,10 @@ def decay_scan(a: jax.Array, b: jax.Array, h0: jax.Array | None = None,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "seq_chunk"))
+@functools.partial(jax.jit, static_argnames=("backend", "seq_chunk",
+                                             "interpret"))
 def wkv6(r, k, v, w, u, s0=None, backend: str = "jnp",
-         seq_chunk: int = 1024):
+         seq_chunk: int = 1024, interpret: bool = False):
     """r,k,w: (B,S,H,K); v: (B,S,H,V); u: (H,K); s0: (B,H,K,V).
     Returns (out (B,S,H,V) f32, s_final f32)."""
     B, S, H, K = r.shape
@@ -129,7 +133,7 @@ def wkv6(r, k, v, w, u, s0=None, backend: str = "jnp",
     for c0 in range(0, S, seq_chunk):
         sl = slice(c0, min(c0 + seq_chunk, S))
         o, sf = wkv6_pallas(rf[:, sl], kf[:, sl], vf[:, sl], wf[:, sl],
-                            uf, sf, interpret=not _on_tpu())
+                            uf, sf, interpret=interpret)
         outs.append(o)
     out = jnp.concatenate(outs, axis=1)
     out = out.reshape(B, H, S, V).transpose(0, 2, 1, 3)

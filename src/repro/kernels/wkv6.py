@@ -37,7 +37,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref):
     sT_ref[0, :, :] = sT
 
 
-def wkv6_pallas(r, k, v, w, u, s0, *, interpret: bool = True):
+def wkv6_pallas(r, k, v, w, u, s0, *, interpret: bool):
     """r,k,w: (BH, S, K); v: (BH, S, V); u: (BH, K); s0: (BH, K, V)
     -> (o (BH, S, V), sT (BH, K, V)), all float32."""
     BH, S, K = r.shape

@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import INPUT_SHAPES, get_config, list_configs
-from repro.core.compat import cost_analysis_dict
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                                make_production_mesh)
 from repro.models.model import (adapt_for_shape, cache_len_for, input_specs,
@@ -208,11 +207,11 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         ustep, uargs, _ = build_step(ucfg, shape)
         with unroll_chunks_for_analysis():
             ulowered = jax.jit(ustep).lower(*uargs)
-        ucost = cost_analysis_dict(ulowered.cost_analysis())
+        ucost = ulowered.cost_analysis()
         flops = float(ucost.get("flops", 0.0)) / n_chips
         bytes_accessed = float(ucost.get("bytes accessed", 0.0)) / n_chips
     else:
-        cost = cost_analysis_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         flops = float(cost.get("flops", 0.0))
         bytes_accessed = float(cost.get("bytes accessed", 0.0))
 

@@ -15,12 +15,12 @@ _SCRIPT = textwrap.dedent("""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs.base import get_config, reduced, InputShape
-    from repro.core.compat import cost_analysis_dict
     from repro.launch.dryrun import build_step, shardings_for
     from repro.launch.hlo_analysis import collective_bytes
+    from repro.launch.mesh import make_mesh
     from repro.sharding.partition import use_rules
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     results = {}
     for arch, shape in [("qwen3-0.6b", InputShape("t", 64, 8, "train")),
                         ("olmoe-1b-7b", InputShape("d", 64, 8, "decode")),
@@ -35,8 +35,7 @@ _SCRIPT = textwrap.dedent("""
                                ).lower(*args_sds).compile()
         coll = collective_bytes(compiled.as_text())
         results[arch] = {
-            "flops": cost_analysis_dict(compiled.cost_analysis())
-                     .get("flops", 0.0),
+            "flops": compiled.cost_analysis().get("flops", 0.0),
             "coll": coll["_total_bytes"],
         }
     print("RESULT:" + json.dumps(results))

@@ -1,5 +1,6 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracles,
-swept over shapes and dtypes; SHA-256 additionally vs hashlib."""
+"""Per-kernel validation: Pallas in interpret mode (asked for by name,
+``interpret=True``) vs pure-jnp oracles, swept over shapes and dtypes;
+SHA-256 additionally vs hashlib."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +19,8 @@ class TestSha256:
         got_jnp = np.asarray(ops.sha256_words(jnp.asarray(msg),
                                               backend="jnp"))
         got_pl = np.asarray(ops.sha256_words(jnp.asarray(msg),
-                                             backend="pallas"))
+                                             backend="pallas",
+                                             interpret=True))
         np.testing.assert_array_equal(got_jnp, gt)
         np.testing.assert_array_equal(got_pl, gt)
 
@@ -47,7 +49,8 @@ class TestDecayScan:
         a = jnp.asarray(rs.uniform(0.3, 1.0, shape).astype(dtype))
         b = jnp.asarray(rs.normal(size=shape).astype(dtype))
         h0 = jnp.asarray(rs.normal(size=(B, C)).astype(dtype))
-        got, gotT = ops.decay_scan(a, b, h0, backend="pallas", seq_chunk=16)
+        got, gotT = ops.decay_scan(a, b, h0, backend="pallas", seq_chunk=16,
+                                     interpret=True)
         want = ref.decay_scan_ref(a, b, h0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
@@ -96,7 +99,7 @@ class TestWkv6:
         u = mk(H, K)
         s0 = mk(B, H, K, V)
         got_o, got_s = ops.wkv6(r, k, v, w, u, s0, backend="pallas",
-                                seq_chunk=7)
+                                seq_chunk=7, interpret=True)
         want_o, want_s = ref.wkv6_ref(r, k, v, w, u, s0)
         np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
                                    rtol=1e-4, atol=1e-4)
@@ -135,7 +138,7 @@ class TestFlashAttention:
         k = jnp.asarray(rs.normal(size=(B, T, Kv, hd)).astype(np.float32))
         v = jnp.asarray(rs.normal(size=(B, T, Kv, hd)).astype(np.float32))
         got = ops.flash_attention(q, k, v, causal=causal, backend="pallas",
-                                  bq=16, bk=16)
+                                  bq=16, bk=16, interpret=True)
         want = chunked_attention(q, k, v, causal=causal, chunk=8)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
@@ -147,6 +150,6 @@ class TestFlashAttention:
         k = jnp.full((B, S, H, hd), 30.0)
         v = jnp.ones((B, S, H, hd))
         out = ops.flash_attention(q, k, v, causal=True, backend="pallas",
-                                  bq=8, bk=8)
+                                  bq=8, bk=8, interpret=True)
         assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_allclose(np.asarray(out), 1.0, rtol=1e-5)

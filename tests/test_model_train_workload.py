@@ -130,11 +130,12 @@ class TestShardingInvariance:
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.chain.workloads.model_train import MICRO_CONFIG
+        from repro.launch.mesh import make_mesh
         from repro.sharding.partition import param_shardings
         from repro.train.steps import make_train_state, params_digest, \\
             tree_digest
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         x = np.arange(8 * 16, dtype=np.float32).reshape(8, 16)
         host = {"w": x}
         for spec in [P("data", "model"), P("model", None), P()]:

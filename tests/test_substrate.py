@@ -15,6 +15,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, reduced
 from repro.configs.base import InputShape
 from repro.data.pipeline import SyntheticTokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.models.attention import chunked_attention
 from repro.optim.adamw import adamw_init, adamw_update
 from repro.optim.schedule import cosine_schedule
@@ -98,7 +99,7 @@ class TestSchedule:
 
 class TestShardingRules:
     def _mesh(self):
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1), ("data", "model"))
 
     def test_param_specs_cover_big_matrices(self):
         cfg = reduced(get_config("olmoe-1b-7b"))
@@ -114,10 +115,10 @@ class TestShardingRules:
             assert len(sp) <= len(pv.shape)
 
     def test_divisibility_fallback_replicates(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         # mesh size 1 divides everything; use a fake 16-way check instead
         from repro.sharding.partition import _spec_for
-        big = jax.make_mesh((1, 1), ("data", "model"))
+        big = make_mesh((1, 1), ("data", "model"))
         spec = _spec_for("whisper/pos_table", (1500, 64), big, True)
         assert isinstance(spec, P)
 
