@@ -4,8 +4,8 @@
 Three invariants keep the docs honest:
 
 1. **API coverage** — every name in the ``__all__`` of ``repro``,
-   ``repro.chain``, ``repro.chain.net``, ``repro.chain.workloads`` and
-   ``repro.core`` has a ``### `module.name` `` heading in
+   ``repro.chain``, ``repro.chain.net``, ``repro.chain.workloads``,
+   ``repro.core`` and ``repro.spans`` has a ``### `module.name` `` heading in
    ``docs/api.md`` (a new export without a doc entry fails CI; a doc
    entry for a removed export fails too).
 2. **Docs execute** — every ```` ```python ```` block in README.md and
@@ -33,7 +33,7 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO / "src"))
 
 MODULES = ("repro", "repro.chain", "repro.chain.net",
-           "repro.chain.workloads", "repro.core")
+           "repro.chain.workloads", "repro.core", "repro.spans")
 
 # every file under docs/ must appear here, mapped to how it is kept
 # honest: "blocks" (its ```python blocks execute in this script),

@@ -24,9 +24,9 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro import spans
 from repro.core.authority import ReviewReport, RuntimeAuthority
 from repro.core.difficulty import DifficultyController
 from repro.core.jash import Jash
@@ -421,81 +421,91 @@ class Node:
         SHA-256 when the queue is empty (§3.4).  Pass "optimal",
         "training" or "classic" to select the payload explicitly.
         """
-        t0 = time.perf_counter()
-        if workload in (None, "full", "optimal"):
-            jash, source = self.ra.publish_next()
-            if source == "queued":
-                name = workload or "full"
-            elif workload is None:
-                name = "classic"            # §3.4 fallback, default policy
+        with spans.span("node.mine_block", node_id=self.node_id,
+                        height=self.ledger.height) as blk:
+            if workload in (None, "full", "optimal"):
+                with spans.span("ra.publish"):
+                    jash, source = self.ra.publish_next()
+                if source == "queued":
+                    name = workload or "full"
+                elif workload is None:
+                    name = "classic"        # §3.4 fallback, default policy
+                else:
+                    raise ChainError(
+                        f"workload {workload!r} requested explicitly but "
+                        "the RA queue is empty — submit a jash first or "
+                        "mine with the default policy (workload=None) for "
+                        "the classic fallback")
             else:
+                if workload not in self.workloads:
+                    raise ChainError(f"unknown workload {workload!r} "
+                                     f"(have {sorted(self.workloads)})")
+                jash, source, name = None, workload, workload
+
+            wl = self.workloads[name]
+            ctx = BlockContext(height=self.ledger.height,
+                               prev_hash=self.ledger.tip_hash,
+                               node_id=self.node_id, jash=jash,
+                               source=source, work=self.work,
+                               block_reward=self.block_reward,
+                               mesh=self.mesh, lanes=self.n_lanes)
+            try:
+                with spans.span("workload.prepare"):
+                    work = wl.prepare(ctx)
+                with spans.span("workload.mine"):
+                    payload = wl.mine(work)
+                with spans.span("workload.verify"):
+                    ok = wl.verify(payload)
+            except Exception:
+                if source == "queued":
+                    self.ra.requeue(jash)       # don't lose the submission
+                raise
+            if not ok:
+                if source == "queued":
+                    self.ra.requeue(jash)
                 raise ChainError(
-                    f"workload {workload!r} requested explicitly but the "
-                    "RA queue is empty — submit a jash first or mine with "
-                    "the default policy (workload=None) for the classic "
-                    "fallback")
-        else:
-            if workload not in self.workloads:
-                raise ChainError(f"unknown workload {workload!r} "
-                                 f"(have {sorted(self.workloads)})")
-            jash, source, name = None, workload, workload
+                    f"self-mined {name} block at height {ctx.height} "
+                    "failed verification — refusing to commit")
+            record, rewards = self._commit(payload)
+            if self.verify_cache is not None and not is_stateful(wl):
+                # the self-verification above counts for the trust domain
+                self.verify_cache.add(record.block_hash, payload,
+                                      height=record.height)
 
-        wl = self.workloads[name]
-        ctx = BlockContext(height=self.ledger.height,
-                           prev_hash=self.ledger.tip_hash,
-                           node_id=self.node_id, jash=jash, source=source,
-                           work=self.work, block_reward=self.block_reward,
-                           mesh=self.mesh, lanes=self.n_lanes)
-        try:
-            payload = wl.mine(wl.prepare(ctx))
-            ok = wl.verify(payload)
-        except Exception:
-            if source == "queued":
-                self.ra.requeue(jash)       # don't lose the submission
-            raise
-        if not ok:
-            if source == "queued":
-                self.ra.requeue(jash)
-            raise ChainError(
-                f"self-mined {name} block at height {ctx.height} failed "
-                "verification — refusing to commit")
-        record, rewards = self._commit(payload)
-        if self.verify_cache is not None and not is_stateful(wl):
-            # the self-verification above counts for the trust domain
-            self.verify_cache.add(record.block_hash, payload,
-                                  height=record.height)
-
-        dt = time.perf_counter() - t0
-        if self.difficulty is not None:
-            self.difficulty.observe(dt)
-            if self.work is not None:
-                self.work = self.difficulty.propose_work(self.work)
-        return BlockReceipt(record=record, payload=payload, rewards=rewards,
-                            block_time_s=dt)
+            # the block's time is the elapsed time of its own span
+            dt = blk.elapsed_s()
+            if self.difficulty is not None:
+                self.difficulty.observe(dt)
+                if self.work is not None:
+                    self.work = self.difficulty.propose_work(self.work)
+            return BlockReceipt(record=record, payload=payload,
+                                rewards=rewards, block_time_s=dt)
 
     def _commit(self, payload: BlockPayload
                 ) -> Tuple[BlockRecord, RewardEntries]:
-        blk = self.ledger.append(
-            jash_id=payload.jash_id, mode=payload.workload,
-            merkle=payload.merkle_root, winner=payload.winner,
-            best_res=payload.best_res, n_results=payload.n_results,
-            state_digest=payload.state_digest)
-        self._hash_index.add(blk.block_hash)
-        self._payloads[blk.height] = payload
-        if self.store is not None and not self._journal_mute:
-            self.store.append_commit(blk, payload)
-        rewards = self.workloads[payload.workload].reward(self.book, payload)
-        # during a fork-choice rebuild the stateful workloads already
-        # sit at the *tail end* state (batched verification replayed
-        # them before the commit loop), so a mid-loop checkpoint would
-        # pair an intermediate height with end-of-chain trainer state —
-        # consider_chain suppresses the ring and pushes one consistent
-        # checkpoint at the adopted tip instead
-        if (self.snapshot_interval > 0 and not self._in_rebuild
-                and self.ledger.height % self.snapshot_interval == 0):
-            self._push_snapshot()
-        self._advance_finality()
-        return BlockRecord.from_block(blk), rewards
+        with spans.span("node.commit"):
+            blk = self.ledger.append(
+                jash_id=payload.jash_id, mode=payload.workload,
+                merkle=payload.merkle_root, winner=payload.winner,
+                best_res=payload.best_res, n_results=payload.n_results,
+                state_digest=payload.state_digest)
+            self._hash_index.add(blk.block_hash)
+            self._payloads[blk.height] = payload
+            if self.store is not None and not self._journal_mute:
+                self.store.append_commit(blk, payload)
+            rewards = self.workloads[payload.workload].reward(
+                self.book, payload)
+            # during a fork-choice rebuild the stateful workloads already
+            # sit at the *tail end* state (batched verification replayed
+            # them before the commit loop), so a mid-loop checkpoint
+            # would pair an intermediate height with end-of-chain trainer
+            # state — consider_chain suppresses the ring and pushes one
+            # consistent checkpoint at the adopted tip instead
+            if (self.snapshot_interval > 0 and not self._in_rebuild
+                    and self.ledger.height % self.snapshot_interval == 0):
+                self._push_snapshot()
+            self._advance_finality()
+            return BlockRecord.from_block(blk), rewards
 
     # -- finality ------------------------------------------------------
     @property
@@ -632,32 +642,36 @@ class Node:
         the ring's key for it; without one, ``origin`` (the transport-
         level sender the in-process network passes) must equal the
         claimed origin — the unsigned stand-in for the same check."""
-        if (block.height != self.ledger.height
-                or block.prev_hash != self.ledger.tip_hash):
-            return False
-        if payload.block_reward != self.block_reward:
-            return False
-        if self.keyring is not None:
-            if announce is None or not announce.verify(
-                    self.keyring, block, payload):
+        with spans.span("node.receive", node_id=self.node_id,
+                        height=block.height):
+            if (block.height != self.ledger.height
+                    or block.prev_hash != self.ledger.tip_hash):
                 return False
-        elif origin is not None and payload.origin != origin:
-            return False
-        if not self._payload_matches(block, payload):
-            return False
-        wl = self.workloads.get(payload.workload)
-        if wl is None:
-            return False
-        shareable = not is_stateful(wl) and self.verify_cache is not None
-        if not (shareable
-                and self.verify_cache.check(block.block_hash, payload)):
-            if not wl.verify(payload):
+            if payload.block_reward != self.block_reward:
                 return False
-            if shareable:
-                self.verify_cache.add(block.block_hash, payload,
-                                      height=block.height)
-        self._commit(payload)
-        return True
+            if self.keyring is not None:
+                if announce is None or not announce.verify(
+                        self.keyring, block, payload):
+                    return False
+            elif origin is not None and payload.origin != origin:
+                return False
+            if not self._payload_matches(block, payload):
+                return False
+            wl = self.workloads.get(payload.workload)
+            if wl is None:
+                return False
+            shareable = not is_stateful(wl) and self.verify_cache is not None
+            if not (shareable
+                    and self.verify_cache.check(block.block_hash, payload)):
+                with spans.span("workload.verify"):
+                    ok = wl.verify(payload)
+                if not ok:
+                    return False
+                if shareable:
+                    self.verify_cache.add(block.block_hash, payload,
+                                          height=block.height)
+            self._commit(payload)
+            return True
 
     def consider_chain(self, blocks: Sequence[Block],
                        payloads: Sequence[BlockPayload]) -> bool:

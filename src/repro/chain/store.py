@@ -45,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.executor import FullResult
 from repro.core.jash import Jash, JashMeta
 from repro.core.ledger import Block
@@ -450,10 +451,13 @@ class ChainStore:
 
     def append_commit(self, block: Block, payload: BlockPayload) -> None:
         """Journal one committed block (header + payload evidence)."""
-        w = _W()
-        _enc_block(w, block)
-        _enc_payload(w, payload)
-        self._append(self._frame(REC_COMMIT, bytes(w.buf)))
+        with spans.span("store.append_commit"):
+            w = _W()
+            _enc_block(w, block)
+            _enc_payload(w, payload)
+            frame = self._frame(REC_COMMIT, bytes(w.buf))
+            self._append(frame)
+            spans.count("journal_bytes", len(frame))
 
     def append_truncate(self, height: int) -> None:
         """Journal a fork-choice truncation: the chain now ends at

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import spans
 from repro.chain.workload import (BlockContext, BlockPayload, PreparedWork,
                                   RewardEntries, _apply_rewards, global_miner)
 from repro.configs import get_config
@@ -243,27 +244,34 @@ class ModelTrainingWorkload:
         Functional: returns ``(new_state, proof, root, loss)`` without
         mutating the workload, so a failed verify needs no rollback."""
         state = self._ensure_state()
-        batches = [self.pipeline.microbatch(height, m)
-                   for m in range(self.block_microsteps)]
+        with spans.span("model_train.batches"):
+            batches = [self.pipeline.microbatch(height, m)
+                       for m in range(self.block_microsteps)]
+            stacked = self._stack_batches(batches)
         step = _block_step(self.cfg, self.hp, self.block_microsteps,
                            self.mesh)
-        with use_rules(self.mesh):
-            new_state, stacked_metrics = step(state,
-                                              self._stack_batches(batches))
-        metrics = jax.device_get(stacked_metrics)
-        rows = []
-        for m in range(self.block_microsteps):
-            mh = hashlib.sha256()
-            for k in sorted(metrics):
-                mh.update(k.encode())
-                mh.update(np.asarray(metrics[k][m], np.float64).tobytes())
-            bd = bytes.fromhex(tree_digest(batches[m]))
-            rows.append(np.frombuffer(bd + mh.digest(), np.uint8))
-        proof = np.stack(rows)
-        root = merkle_root([
-            self._leaf(height, m, proof[m, :32].tobytes(),
-                       proof[m, 32:].tobytes())
-            for m in range(self.block_microsteps)])
+        # the host waits here for the device to run the block's steps
+        with spans.span("model_train.step"):
+            with use_rules(self.mesh):
+                new_state, stacked_metrics = step(state, stacked)
+            metrics = jax.device_get(stacked_metrics)
+            spans.count("d2h_bytes",
+                        sum(v.nbytes for v in metrics.values()))
+        with spans.span("model_train.proof"):
+            rows = []
+            for m in range(self.block_microsteps):
+                mh = hashlib.sha256()
+                for k in sorted(metrics):
+                    mh.update(k.encode())
+                    mh.update(np.asarray(metrics[k][m],
+                                         np.float64).tobytes())
+                bd = bytes.fromhex(tree_digest(batches[m]))
+                rows.append(np.frombuffer(bd + mh.digest(), np.uint8))
+            proof = np.stack(rows)
+            root = merkle_root([
+                self._leaf(height, m, proof[m, :32].tobytes(),
+                           proof[m, 32:].tobytes())
+                for m in range(self.block_microsteps)])
         loss = float(np.asarray(metrics["loss"][-1], np.float64))
         return new_state, batches, proof, root, loss
 

@@ -46,6 +46,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import spans
 from repro.core.jash import Jash
 from repro.kernels.merkle import bswap32, merkle_root_from_digests
 from repro.kernels.ops import sha256_words
@@ -201,16 +202,20 @@ def run_full(jash: Jash, *, mesh: Optional[Mesh] = None,
 
     res_parts, hash_parts, leaf_parts = [], [], []
     for c in range(n_chunks):
-        width = chunk if c < n_chunks - 1 else tail
-        args_c = jnp.arange(c * chunk, c * chunk + width, dtype=jnp.uint32)
-        if ctx is not None:
-            with ctx:
+        with spans.span("executor.run_full.chunk"):
+            width = chunk if c < n_chunks - 1 else tail
+            args_c = jnp.arange(c * chunk, c * chunk + width,
+                                dtype=jnp.uint32)
+            if ctx is not None:
+                with ctx:
+                    r, h, d = jitted(args_c)
+            else:
                 r, h, d = jitted(args_c)
-        else:
-            r, h, d = jitted(args_c)
-        res_parts.append(np.asarray(r))
-        hash_parts.append(np.asarray(h))
-        leaf_parts.append(np.asarray(d))
+            res_parts.append(np.asarray(r))
+            hash_parts.append(np.asarray(h))
+            leaf_parts.append(np.asarray(d))
+            spans.count("d2h_bytes", res_parts[-1].nbytes
+                        + hash_parts[-1].nbytes + leaf_parts[-1].nbytes)
 
     cat = (lambda ps: ps[0][:n] if len(ps) == 1
            else np.concatenate(ps, axis=0)[:n])
@@ -283,44 +288,47 @@ def run_optimal(jash: Jash, *, mesh: Optional[Mesh] = None,
     independent of the lane count, so a verifier replaying with
     ``lanes=1`` reproduces a multi-lane miner's commitment bit-exactly.
     """
-    if lanes < 1:
-        raise ValueError(f"lanes must be >= 1, got {lanes}")
-    n = jash.meta.n_args
-    axes = _miner_axes(mesh)
+    with spans.span("executor.run_optimal"):
+        if lanes < 1:
+            raise ValueError(f"lanes must be >= 1, got {lanes}")
+        n = jash.meta.n_args
+        axes = _miner_axes(mesh)
 
-    if mesh is not None and axes:
-        if lanes != 1:
-            raise ValueError(
-                "lanes is the single-device miner partition; a sharded "
-                "mesh already defines the miner fleet via its axes — use "
-                "one or the other")
-        n_miners = int(np.prod([mesh.shape[a] for a in axes]))
-        n_pad = -n % n_miners
-        args = jnp.arange(n + n_pad, dtype=jnp.uint32)
-        valid = args < n
+        if mesh is not None and axes:
+            if lanes != 1:
+                raise ValueError(
+                    "lanes is the single-device miner partition; a "
+                    "sharded mesh already defines the miner fleet via its "
+                    "axes — use one or the other")
+            n_miners = int(np.prod([mesh.shape[a] for a in axes]))
+            n_pad = -n % n_miners
+            args = jnp.arange(n + n_pad, dtype=jnp.uint32)
+            valid = args < n
 
-        def sharded(args_all, valid_all):
-            w0, w1, arg, res = _eval_and_reduce(jash.fn, args_all,
-                                                valid_all)
-            w0g = jax.lax.all_gather(w0, axes)
-            w1g = jax.lax.all_gather(w1, axes)
-            argsg = jax.lax.all_gather(arg, axes)
-            resg = jax.lax.all_gather(res, axes)
-            best = _lex_argmin(w0g, w1g)
-            return argsg[best], resg[best], best.astype(jnp.int32)
+            def sharded(args_all, valid_all):
+                w0, w1, arg, res = _eval_and_reduce(jash.fn, args_all,
+                                                    valid_all)
+                w0g = jax.lax.all_gather(w0, axes)
+                w1g = jax.lax.all_gather(w1, axes)
+                argsg = jax.lax.all_gather(arg, axes)
+                resg = jax.lax.all_gather(res, axes)
+                best = _lex_argmin(w0g, w1g)
+                return argsg[best], resg[best], best.astype(jnp.int32)
 
-        fn = shard_map(sharded, mesh=mesh, in_specs=(P(axes), P(axes)),
-                       out_specs=(P(), P(), P()), check_vma=False)
-        with mesh:
-            best_arg, best_res, winner = jax.jit(fn)(args, valid)
-    else:
-        lanes = min(lanes, n)
-        n_pad = -n % lanes
-        args = jnp.arange(n + n_pad, dtype=jnp.uint32)
-        valid = args < n
-        best_arg, best_res, winner = _optimal_executor(jash.fn, lanes)(
-            args, valid)
+            fn = shard_map(sharded, mesh=mesh, in_specs=(P(axes), P(axes)),
+                           out_specs=(P(), P(), P()), check_vma=False)
+            with mesh:
+                best_arg, best_res, winner = jax.jit(fn)(args, valid)
+        else:
+            lanes = min(lanes, n)
+            n_pad = -n % lanes
+            args = jnp.arange(n + n_pad, dtype=jnp.uint32)
+            valid = args < n
+            best_arg, best_res, winner = _optimal_executor(jash.fn, lanes)(
+                args, valid)
 
-    return OptimalResult(best_arg=int(best_arg),
-                         best_res=np.atleast_1d(np.asarray(best_res)),
-                         winner=int(winner), n_evaluated=n)
+        # the host waits here for the device's search
+        with spans.span("executor.fetch"):
+            return OptimalResult(best_arg=int(best_arg),
+                                 best_res=np.atleast_1d(np.asarray(best_res)),
+                                 winner=int(winner), n_evaluated=n)

@@ -18,6 +18,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import spans
+
 
 def sha256_hex(data: bytes) -> str:
     return hashlib.sha256(data).hexdigest()
@@ -46,13 +48,17 @@ def merkle_root(leaves: Sequence[bytes], *, backend: str = "auto") -> str:
                          "(expected 'auto', 'device' or 'hashlib')")
     if not leaves:
         return sha256_hex(b"")
-    level = [hashlib.sha256(x).digest() for x in leaves]
-    while len(level) > 1:
-        if len(level) % 2:
-            level.append(level[-1])
-        level = [hashlib.sha256(level[i] + level[i + 1]).digest()
-                 for i in range(0, len(level), 2)]
-    return level[0].hex()
+    with spans.span("ledger.merkle_root"):
+        level = [hashlib.sha256(x).digest() for x in leaves]
+        hashed = sum(len(x) for x in leaves)
+        while len(level) > 1:
+            if len(level) % 2:
+                level.append(level[-1])
+            level = [hashlib.sha256(level[i] + level[i + 1]).digest()
+                     for i in range(0, len(level), 2)]
+            hashed += 32 * len(level) * 2
+        spans.count("hashed_bytes", hashed)
+        return level[0].hex()
 
 
 def merkle_proof(leaves: Sequence[bytes], index: int, *,

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.executor import FullResult, _as_words
 from repro.core.jash import Jash
 from repro.core.ledger import merkle_proof, merkle_root, verify_merkle_proof
@@ -69,16 +70,17 @@ def _sample_indices(n: int, *, fraction: float, seed: int,
 def quorum_verify(jash: Jash, full: FullResult, *, fraction: float = 0.05,
                   seed: int = 0, min_checks: int = 4) -> VerifyReport:
     """Deterministic re-execution of a random subset of args."""
-    idx = _sample_indices(len(full.args), fraction=fraction, seed=seed,
-                          min_checks=min_checks)
+    with spans.span("verify.quorum_verify"):
+        idx = _sample_indices(len(full.args), fraction=fraction, seed=seed,
+                              min_checks=min_checks)
 
-    args = jnp.asarray(full.args[idx], jnp.uint32)
-    recomputed = np.asarray(_recompute_fn(jash.fn)(args))
+        args = jnp.asarray(full.args[idx], jnp.uint32)
+        recomputed = np.asarray(_recompute_fn(jash.fn)(args))
 
-    mism = [int(full.args[i]) for j, i in enumerate(idx)
-            if not np.array_equal(recomputed[j], full.results[i])]
-    return VerifyReport(n_checked=len(idx), n_mismatch=len(mism),
-                        ok=not mism, mismatched_args=tuple(mism))
+        mism = [int(full.args[i]) for j, i in enumerate(idx)
+                if not np.array_equal(recomputed[j], full.results[i])]
+        return VerifyReport(n_checked=len(idx), n_mismatch=len(mism),
+                            ok=not mism, mismatched_args=tuple(mism))
 
 
 def quorum_verify_batched(pairs: Sequence[Tuple[Jash, FullResult]], *,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import InputShape, ModelConfig
 from repro.models.model import adapt_for_shape, build_model, cache_len_for
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update
@@ -46,16 +47,23 @@ def _canonical_leaf(arr: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(arr)
 
 
-def canonical_tree_bytes(tree: Any):
-    """Yield the canonical byte framing of a pytree, leaf by leaf:
-    ``path | dtype | ndim | shape | little-endian C-order data``.
+def _gathered(tree: Any) -> list:
+    """``(path, host array)`` of every leaf of ``tree``, gathered to host
+    (``sharding.partition.gather_tree``), so that what is framed is
+    sharding- and layout-invariant."""
+    with spans.span("tree_digest.fetch"):
+        flat, _ = jax.tree_util.tree_flatten_with_path(gather_tree(tree))
+        spans.count("d2h_bytes", sum(leaf.nbytes for _, leaf in flat))
+    return flat
+
+
+def _canonical_tree_bytes(flat: list):
+    """Yield the canonical byte framing of a gathered pytree, leaf by
+    leaf: ``path | dtype | ndim | shape | little-endian C-order data``.
 
     The path prefix keeps structurally-different trees with identical
     flattened values apart; the dtype+shape frame keeps reinterpreted
-    buffers apart (``float32[4]`` never collides with ``uint8[16]``).
-    Leaves are gathered to host first (``sharding.partition.gather_tree``),
-    so the stream is sharding- and layout-invariant."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(gather_tree(tree))
+    buffers apart (``float32[4]`` never collides with ``uint8[16]``)."""
     for path, leaf in flat:
         arr = _canonical_leaf(np.asarray(leaf))
         pstr = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
@@ -67,12 +75,18 @@ def canonical_tree_bytes(tree: Any):
 
 
 def tree_digest(tree: Any) -> str:
-    """sha256 hex digest of ``canonical_tree_bytes(tree)`` — the generic
-    bit-exact commitment for any value pytree (params, batches, metric
-    stacks).  Deterministic across processes, platforms, and shardings."""
-    h = hashlib.sha256()
-    for chunk in canonical_tree_bytes(tree):
-        h.update(chunk)
+    """sha256 hex digest of the canonical bytes of ``tree`` — the
+    generic bit-exact commitment for any value pytree (params, batches,
+    metric stacks).  Deterministic across processes, platforms, and
+    shardings."""
+    flat = _gathered(tree)
+    with spans.span("tree_digest.hash"):
+        h = hashlib.sha256()
+        n = 0
+        for chunk in _canonical_tree_bytes(flat):
+            h.update(chunk)
+            n += len(chunk)
+        spans.count("hashed_bytes", n)
     return h.hexdigest()
 
 
@@ -84,7 +98,8 @@ def params_digest(state_or_params: Any) -> str:
     same weights."""
     params = (state_or_params.params
               if isinstance(state_or_params, TrainState) else state_or_params)
-    return tree_digest(params)
+    with spans.span("params_digest"):
+        return tree_digest(params)
 
 
 @dataclasses.dataclass(frozen=True)
