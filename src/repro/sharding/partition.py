@@ -107,24 +107,23 @@ def constrain(x: jax.Array, *names) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def gather_tree(tree: Any) -> Any:
-    """Fetch every leaf to host memory as a plain ``np.ndarray``,
-    reassembling sharded ``jax.Array``s from their addressable shards.
+def gather_tree_async(tree: Any) -> list:
+    """``(path, leaf)`` of every leaf of ``tree`` in flatten order, with
+    the copy to host memory of every ``jax.Array`` leaf already started,
+    all of them before the caller waits on the first.
 
     This is the *gather* half of the gather-then-hash digest contract:
-    any digest over training state must hash the globally-assembled
-    values, never per-device buffers, so the result is invariant to the
-    mesh shape and device layout the producer happened to run on (a
+    ``np.asarray(leaf)`` then waits for that leaf's bytes alone and gives
+    its globally-assembled value, reassembled from the addressable shards
+    of a sharded array, so a digest over training state is invariant to
+    the mesh shape and device layout the producer happened to run on (a
     1-device CPU node and an 8-way FSDP node must commit bit-identical
-    ``state_digest``s for the same params)."""
-    import numpy as _np
-
-    def gather(leaf):
+    ``state_digest``s for the same params).  Host leaves pass through."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    for _, leaf in flat:
         if isinstance(leaf, jax.Array):
-            return _np.asarray(jax.device_get(leaf))
-        return _np.asarray(leaf)
-
-    return jax.tree.map(gather, tree)
+            leaf.copy_to_host_async()
+    return flat
 
 
 # ---------------------------------------------------------------------------

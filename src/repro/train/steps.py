@@ -25,7 +25,7 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.models.model import adapt_for_shape, build_model, cache_len_for
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update
 from repro.optim.schedule import cosine_schedule
-from repro.sharding.partition import gather_tree
+from repro.sharding.partition import gather_tree_async
 
 
 class TrainState(NamedTuple):
@@ -41,52 +41,51 @@ class TrainState(NamedTuple):
 def _canonical_leaf(arr: np.ndarray) -> np.ndarray:
     """Little-endian, C-contiguous view of ``arr`` — the only byte order
     a digest may ever see, regardless of host endianness or the device
-    layout the array came back from."""
+    layout the array came back from.  Copies only where it must; a 0-d
+    leaf comes back with shape ``(1,)``, as the committed framing has it."""
     if arr.dtype.str.startswith(">"):
         arr = arr.astype(arr.dtype.newbyteorder("<"))
     return np.ascontiguousarray(arr)
 
 
-def _gathered(tree: Any) -> list:
-    """``(path, host array)`` of every leaf of ``tree``, gathered to host
-    (``sharding.partition.gather_tree``), so that what is framed is
-    sharding- and layout-invariant."""
-    with spans.span("tree_digest.fetch"):
-        flat, _ = jax.tree_util.tree_flatten_with_path(gather_tree(tree))
-        spans.count("d2h_bytes", sum(leaf.nbytes for _, leaf in flat))
-    return flat
-
-
-def _canonical_tree_bytes(flat: list):
-    """Yield the canonical byte framing of a gathered pytree, leaf by
-    leaf: ``path | dtype | ndim | shape | little-endian C-order data``.
+def _leaf_header(path, arr: np.ndarray) -> bytes:
+    """The framing in front of a leaf's data: ``path | dtype | ndim |
+    shape``.
 
     The path prefix keeps structurally-different trees with identical
     flattened values apart; the dtype+shape frame keeps reinterpreted
     buffers apart (``float32[4]`` never collides with ``uint8[16]``)."""
-    for path, leaf in flat:
-        arr = _canonical_leaf(np.asarray(leaf))
-        pstr = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                        for k in path)
-        yield pstr.encode() + b"\x00" + arr.dtype.str.encode() + b"\x00"
-        yield np.int64(arr.ndim).tobytes()
-        yield np.asarray(arr.shape, np.int64).tobytes()
-        yield arr.tobytes(order="C")
+    pstr = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in path)
+    return (pstr.encode() + b"\x00" + arr.dtype.str.encode() + b"\x00"
+            + np.int64(arr.ndim).tobytes()
+            + np.asarray(arr.shape, np.int64).tobytes())
 
 
 def tree_digest(tree: Any) -> str:
     """sha256 hex digest of the canonical bytes of ``tree`` — the
     generic bit-exact commitment for any value pytree (params, batches,
     metric stacks).  Deterministic across processes, platforms, and
-    shardings."""
-    flat = _gathered(tree)
-    with spans.span("tree_digest.hash"):
-        h = hashlib.sha256()
-        n = 0
-        for chunk in _canonical_tree_bytes(flat):
-            h.update(chunk)
-            n += len(chunk)
-        spans.count("hashed_bytes", n)
+    shardings.
+
+    The stream is every leaf's header and its little-endian C-order
+    data, leaf by leaf in flatten order.  Every device leaf's copy to the
+    host starts up front (``sharding.partition.gather_tree_async``), so
+    later leaves cross while ``hashlib``, which drops the GIL, hashes
+    earlier ones in place through a ``uint8`` view."""
+    h = hashlib.sha256()
+    for path, leaf in gather_tree_async(tree):
+        # only the wait for bytes still in flight is exposed here
+        with spans.span("tree_digest.fetch"):
+            arr = np.asarray(leaf)
+            if isinstance(leaf, jax.Array):
+                spans.count("d2h_bytes", arr.nbytes)
+        with spans.span("tree_digest.hash"):
+            arr = _canonical_leaf(arr)
+            header = _leaf_header(path, arr)
+            h.update(header)
+            h.update(arr.reshape(-1).view(np.uint8))
+            spans.count("hashed_bytes", len(header) + arr.nbytes)
     return h.hexdigest()
 
 

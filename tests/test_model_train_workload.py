@@ -13,12 +13,16 @@ convergence, crash recovery, mid-chain reorg).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import os
+import struct
 import subprocess
 import sys
 import textwrap
+import tracemalloc
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
@@ -120,6 +124,78 @@ class TestDigestCanonicalization:
         state = make_train_state(micro_wl().cfg, jax.random.key(1))
         host = jax.tree.map(np.asarray, state.params)
         assert params_digest(host) == params_digest(state.params)
+
+
+def _bf16_jax():
+    x = jnp.asarray([[1.0, -2.0, 0.5], [3.0, 0.0, -0.25]], jnp.bfloat16)
+    bits = struct.pack("<6H", 0x3F80, 0xC000, 0x3F00, 0x4040, 0x0000,
+                       0xBE80)
+    return {"w": x}, [("w", "<V2", (2, 3), bits)]
+
+
+def _f32_numpy():
+    return ({"x": np.arange(4, dtype="<f4")},
+            [("x", "<f4", (4,), struct.pack("<4f", 0, 1, 2, 3))])
+
+
+def _scalar():
+    # a 0-d leaf is framed as shape (1,), as np.ascontiguousarray has
+    # always made it
+    return ({"s": np.float64(1.5)},
+            [("s", "<f8", (1,), struct.pack("<d", 1.5))])
+
+
+def _big_endian():
+    return ({"b": np.array([1, -2, 3], ">i4")},
+            [("b", "<i4", (3,), struct.pack("<3i", 1, -2, 3))])
+
+
+def _transposed():
+    return ({"t": np.arange(6, dtype="<i2").reshape(2, 3).T},
+            [("t", "<i2", (3, 2), struct.pack("<6h", 0, 3, 1, 4, 2, 5))])
+
+
+def _mixed():
+    tree = {"z": jnp.float32(2.0),
+            "a": [np.array([True, False]), np.int8(-1)],
+            "m": {"k": np.array([7], ">u2")}}
+    return tree, [("a/0", "|b1", (2,), b"\x01\x00"),
+                  ("a/1", "|i1", (1,), b"\xff"),
+                  ("m/k", "<u2", (1,), b"\x07\x00"),
+                  ("z", "<f4", (1,), struct.pack("<f", 2.0))]
+
+
+def _written_out(leaves) -> str:
+    """sha256 of the framing written out by hand, leaf by leaf:
+    ``path | dtype | ndim | shape | little-endian C-order data``."""
+    h = hashlib.sha256()
+    for path, dtype, shape, data in leaves:
+        h.update(path.encode() + b"\x00" + dtype.encode() + b"\x00")
+        h.update(struct.pack("<q", len(shape)))
+        h.update(struct.pack(f"<{len(shape)}q", *shape))
+        h.update(data)
+    return h.hexdigest()
+
+
+class TestDigestStream:
+    @pytest.mark.parametrize("case", [_bf16_jax, _f32_numpy, _scalar,
+                                      _big_endian, _transposed, _mixed],
+                             ids=lambda f: f.__name__.lstrip("_"))
+    def test_digest_is_the_written_out_framing(self, case):
+        tree, leaves = case()
+        assert tree_digest(tree) == _written_out(leaves)
+
+    def test_host_leaf_is_hashed_in_place(self):
+        """Hashing a 64 MB host leaf allocates no copy of its bytes."""
+        x = np.ones(16 << 20, np.float32)
+        tree_digest({"w": x[:16]})             # imports and first spans
+        tracemalloc.start()
+        try:
+            tree_digest({"w": x})
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        assert peak < 4 << 20, peak
 
 
 class TestShardingInvariance:

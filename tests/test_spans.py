@@ -200,8 +200,11 @@ def test_classic_blocks_through_miner_and_verifier():
 
 
 def test_model_train_blocks_through_miner_and_verifier():
+    wls = []
+
     def node(i):
         wl = ModelTrainingWorkload(**MICRO_KWARGS)
+        wls.append(wl)
         return Node(node_id=i, workloads={"model_train": wl},
                     store=ChainStore(), snapshot_interval=0)
 
@@ -209,6 +212,7 @@ def test_model_train_blocks_through_miner_and_verifier():
     mined = [(r, u) for r, u in blocks if r.name == "node.mine_block"]
     got = [(r, u) for r, u in blocks if r.name == "node.receive"]
     assert len(mined) == len(got) == 3
+    params = jax.tree.leaves(wls[0].snapshot()[1].params)
     for root, under in mined:
         assert {r.name for r in under} == MINE_TRAIN
         assert _coverage(root, under) >= 0.9
@@ -223,11 +227,17 @@ def test_model_train_blocks_through_miner_and_verifier():
                  and r.parent == digest.seq]
         hashed = [r for r in under if r.name == "tree_digest.hash"
                   and r.parent == digest.seq]
-        assert len(fetch) == len(hashed) == 1
-        # every parameter byte crosses to the host and is hashed, with
-        # its framing
-        assert 0 < fetch[0].counts["d2h_bytes"] < \
-            hashed[0].counts["hashed_bytes"]
+        # one fetch and one hash span per parameter leaf, in flatten
+        # order: each leaf is hashed once its own bytes have landed
+        assert len(fetch) == len(hashed) == len(params)
+        assert all(f.end_ns <= h.start_ns
+                   for f, h in zip(fetch, hashed))
+        # every parameter byte crosses to the host, counted at its leaf,
+        # and is hashed with its framing
+        assert [f.counts["d2h_bytes"] for f in fetch] == \
+            [p.nbytes for p in params]
+        assert sum(h.counts["hashed_bytes"] for h in hashed) > \
+            sum(p.nbytes for p in params)
     for root, under in got:
         assert {r.name for r in under} >= RECEIVE_TRAIN
 
