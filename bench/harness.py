@@ -144,6 +144,17 @@ def _use_checkout_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
+def _profile_options():
+    """Host spans and device ops, without the profiler's Python tracer
+    (on by default), which records every Python call: it makes code of
+    many small calls, such as a ``hashlib`` Merkle root, three times
+    slower in a traced run and writes hundreds of MB a block."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    return options
+
+
 def _say(*parts) -> None:
     print(*parts, flush=True)
 
@@ -217,7 +228,8 @@ def main(argv=None, *, t0: Optional[float] = None,
 
     if args.trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        jax.profiler.start_trace(TRACE_DIR)
+        jax.profiler.start_trace(TRACE_DIR,
+                                 profiler_options=_profile_options())
     try:
         records = window(system, args.seconds)
     finally:

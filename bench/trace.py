@@ -2,14 +2,17 @@
 
 The profiler writes ``<dir>/plugins/profile/<time>/<host>.xplane.pb``.
 ``load`` keeps from it only what the metrics read: the device planes'
-op and module events, and the benchmark's own host spans (names starting
-with ``bench.``).  ``reduce`` then computes, inside the measured window:
+op and module events, and the host spans of the benchmark (names
+starting with ``bench.``) and of the program (``repro.spans``: ``node.``,
+``workload.``, ``executor.``, ``verify.`` and the rest of
+``SPAN_PREFIXES``).  ``reduce`` then computes, inside the measured window:
 
 * busy time per device: the union of the intervals in which an op ran;
 * device time and count per program (module), by name;
 * device time per op name (the breakdown's ``device_ops``);
-* idle time, each stretch charged to the innermost benchmark host span
-  open over it (the breakdown's ``idle_gaps``).
+* idle time, each stretch charged to the innermost host span, the
+  benchmark's or the program's, open over it (the breakdown's
+  ``idle_gaps``).
 
 The window runs from the start of the first ``bench.block`` span to the
 end of the ``n_blocks``-th: the blocks the harness counted.
@@ -27,7 +30,9 @@ from typing import Dict, List, Optional, Tuple
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "node.", "ra.", "workload.", "executor.",
+                 "verify.", "ledger.", "store.", "params_digest",
+                 "tree_digest.", "model_train.")
 BLOCK_SPAN = "bench.block"
 
 Interval = Tuple[float, float, str]          # (start_ns, end_ns, name)
@@ -37,7 +42,7 @@ Interval = Tuple[float, float, str]          # (start_ns, end_ns, name)
 class Trace:
     # plane name -> {"ops": [...], "modules": [...]}
     devices: Dict[str, Dict[str, List[Interval]]]
-    spans: List[Interval]                     # bench.* host spans
+    spans: List[Interval]                     # host spans, SPAN_PREFIXES
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -69,7 +74,7 @@ def load(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
+                    if ev.name.startswith(SPAN_PREFIXES):
                         spans.append((float(ev.start_ns),
                                       float(ev.start_ns + ev.duration_ns),
                                       ev.name))

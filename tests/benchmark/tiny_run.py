@@ -3,33 +3,36 @@
     python tests/benchmark/tiny_run.py <cell> <fault|none|control> <seconds>
 
 Runs ``bench/run.py``'s harness through its test-only ``Override`` (tiny
-widths, the CPU platform, no compile cache) in a process of its own,
+sizes, the CPU platform, no compile cache) in a process of its own,
 with ``fault`` planted in the program's timed path first.  ``control``
-prints the cell's control readings against its limits instead."""
+prints the cell's control readings against its limits instead.
+
+Each configuration brings its tiny sizes as ``tiny/<config>.json``: the
+``config`` and ``traffic`` patches and the run's ``seconds`` per
+traffic.  A fault that is not in ``FAULTS`` is looked up in the
+configuration's own ``faults/<config>.py``, a function of that name."""
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
 import sys
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "src"))
 
-# tiny widths, the published vocabulary: the loss then has its real
-# scale (about ln 151,936), which the cells' loss limits are set on
-TINY_MODEL = dict(hidden_size=64, num_attention_heads=4,
-                  num_key_value_heads=2, head_dim=16, intermediate_size=128,
-                  num_hidden_layers=2)
-CLASSIC_BITS = 12
 
-TINY = {
-    "qwen3-0.6b": dict(config=TINY_MODEL, traffic={"seq_len": 32,
-                                                   "batch": 2}),
-    "pnpcoin-node": dict(config={"classic_arg_bits": CLASSIC_BITS}),
-}
+def tiny(config: str) -> dict:
+    """The tiny sizes of configuration ``config``."""
+    with open(os.path.join(HERE, "tiny", config + ".json")) as f:
+        return json.load(f)
+
+
+def _classic_bits() -> int:
+    return tiny("pnpcoin-node")["config"]["classic_arg_bits"]
 
 
 # -- faults planted in the program's timed path -----------------------------
@@ -96,8 +99,9 @@ def half_nonces():
     from repro.core.jash import Jash
     from bench.reference import jash as ref
     orig = w.run_optimal
-    winner = ref.classic(CLASSIC_BITS)[0]
-    n = 1 << CLASSIC_BITS
+    bits = _classic_bits()
+    winner = ref.classic(bits)[0]
+    n = 1 << bits
     lo = 0 if winner < n // 2 else n // 2
     cache = {}
 
@@ -124,8 +128,9 @@ def winner_half_only():
     from repro.core.jash import Jash
     from bench.reference import jash as ref
     orig = w.run_optimal
-    n = 1 << CLASSIC_BITS
-    lo = 0 if ref.classic(CLASSIC_BITS)[0] < n // 2 else n // 2
+    bits = _classic_bits()
+    n = 1 << bits
+    lo = 0 if ref.classic(bits)[0] < n // 2 else n // 2
     cache = {}
 
     def patched(jash, **kw):
@@ -148,25 +153,39 @@ FAULTS = {f.__name__: f for f in (frozen_state, half_batch, altered_loss,
                                   winner_half_only)}
 
 
+def fault(config: str, name: str):
+    """Fault ``name``: one of ``FAULTS``, or else a function of
+    ``faults/<config>.py``, loaded by path."""
+    if name in FAULTS:
+        return FAULTS[name]
+    path = os.path.join(HERE, "faults", config + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "bench_faults_" + config.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return getattr(mod, name)
+
+
 def override(cell_name: str):
     from bench.harness import Override, load_cell
     cell = load_cell(cell_name)
+    t = tiny(cell.config["name"])
     return (Override(platform="cpu", compile_cache=False,
-                     **TINY[cell.config["name"]]), cell)
+                     config=t["config"], traffic=t["traffic"]), cell)
 
 
 def main(argv) -> int:
-    cell_name, fault, seconds = argv
+    cell_name, planted, seconds = argv
     ov, cell = override(cell_name)
-    if fault == "control":
+    if planted == "control":
         from bench import harness
         _, system = harness.build(cell, 7, ov)
         got = system.control()
         print(json.dumps({k: {"value": v, "limit": cell.limits.get(k)}
                           for k, v in got.items()}))
         return 0
-    if fault != "none":
-        FAULTS[fault]()
+    if planted != "none":
+        fault(cell.config["name"], planted)()
     from bench.harness import main as run
     return run(["--workload", cell_name, "--seed", "2147483901",
                 "--seconds", seconds, "--trace", "0"], override=ov)
